@@ -278,6 +278,8 @@ def main(argv=None) -> int:
                     help="(local --open-loop) total arrivals")
     args = ap.parse_args(argv)
     if args.backend == "local":
+        from repro.compile_cache import use_compile_cache
+        use_compile_cache()
         if args.open_loop:
             return run_local_open_loop(args)
         return run_local(args)

@@ -528,7 +528,9 @@ class RemoteRunner:
 
     def _start_pool(self, gen: int) -> None:
         # fork: handlers / Workload.fn are closures, so spawn cannot ship
-        # them — the whole runner state is inherited copy-on-write instead
+        # them — the whole runner state is inherited copy-on-write instead.
+        # The pool stays JAX-free: a TPU chip belongs to one process, so a
+        # worker forked from a parent that holds the chip cannot use it.
         ctx = multiprocessing.get_context("fork")
         self._procs = []
         for cloud, n in self._worker_plan():

@@ -246,6 +246,8 @@ def run_sharded(builders: Sequence[Callable[[], Any]],
                 for i in range(shards)]
     nproc = processes if processes is not None else min(
         shards, os.cpu_count() or 1)
+    # fork, JAX-free: shards simulate on SimCloud only (a forked child of a
+    # parent that holds a TPU chip cannot use it)
     ctx = multiprocessing.get_context("fork")
     # maxtasksperchild=1: each worker simulates exactly one shard then exits,
     # returning its (large) resident set to the OS before the next shard runs

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _kernel(log_a_ref, b_ref, h_ref, carry_ref, *, bl: int):
     il = pl.program_id(2)
@@ -80,7 +78,7 @@ def rglru_scan(log_a: jax.Array, b: jax.Array, *, block_l: int = 256,
         out_specs=pl.BlockSpec((1, bl, bw), lambda ib, iw, il: (ib, il, iw)),
         out_shape=jax.ShapeDtypeStruct((bt, l, w), jnp.float32),
         scratch_shapes=[pltpu.VMEM((8, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="rglru_scan",

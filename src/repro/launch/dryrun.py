@@ -137,7 +137,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         rec["compile_s"] = round(time.time() - t1, 2)
 
         mem = compiled.memory_analysis()
-        cost_raw = hlo_cost.xla_cost_analysis(compiled)
+        cost_raw = compiled.cost_analysis()
         if verbose:
             print(mem)
             print({k: v for k, v in cost_raw.items()

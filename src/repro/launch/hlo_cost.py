@@ -41,17 +41,6 @@ _DTYPE_BYTES = {
 _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([\d,]*)\]")
 
 
-def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to a flat dict.
-
-    jax ≤ 0.4.x returns a one-element list of per-device dicts; jax ≥ 0.5
-    returns the dict directly.  Either way an empty analysis becomes ``{}``.
-    """
-    raw = compiled.cost_analysis()
-    if isinstance(raw, (list, tuple)):
-        raw = raw[0] if raw else {}
-    return dict(raw or {})
-
 _ELEMENTWISE = {
     "add", "subtract", "multiply", "divide", "maximum", "minimum", "abs",
     "negate", "select", "compare", "and", "or", "xor", "not", "clamp",

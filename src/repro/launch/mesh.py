@@ -7,28 +7,16 @@ importing this module touches no jax device state — the dry-run must set
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
-
 import jax
+from jax.sharding import AxisType
 
 from repro.parallel.mesh_ctx import MeshCtx
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """``jax.make_mesh`` across versions: ``axis_types=`` exists only where
-    ``jax.sharding.AxisType`` does (jax ≥ 0.5); older jax meshes are
-    implicitly Auto, so plain construction is equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_ctx(mesh, *, fsdp_over_pod: bool = False, **knobs) -> MeshCtx:
@@ -41,4 +29,5 @@ def make_ctx(mesh, *, fsdp_over_pod: bool = False, **knobs) -> MeshCtx:
 
 def make_smoke_mesh(n_data: int = 2, n_model: int = 2):
     """Tiny mesh for CPU tests (requires host-device override ≥ n_data·n_model)."""
-    return make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

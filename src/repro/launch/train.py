@@ -15,6 +15,7 @@ import argparse
 import sys
 
 from repro import configs
+from repro.compile_cache import use_compile_cache
 
 
 def main() -> int:
@@ -36,6 +37,7 @@ def main() -> int:
                     help="kill the primary controller after N chunks "
                          "(failover demo)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     overrides = {}

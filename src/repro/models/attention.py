@@ -319,12 +319,11 @@ def _decode_seqshard(cfg: ModelConfig, q, k_new, v_new, cache_k, cache_v,
         out = (num / den[..., None]).astype(qs.dtype)
         return jnp.moveaxis(out, 3, 1).reshape(bl, l, h, hd), ck, cv
 
-    from repro.parallel.mesh_ctx import shard_map
-    return shard_map(
+    return jax.shard_map(
         shard,
         mesh=ctx.mesh,
         in_specs=(P_(batch), P_(batch), P_(batch),
                   P_(batch, m_ax), P_(batch, m_ax), P_()),
         out_specs=(P_(batch), P_(batch, m_ax), P_(batch, m_ax)),
-        check=False,
+        check_vma=False,
     )(q, k_new, v_new, cache_k, cache_v, pos)

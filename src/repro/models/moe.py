@@ -142,7 +142,6 @@ def apply_ref(params: Dict[str, Any], cfg: ModelConfig, x: jax.Array) -> jax.Arr
 
 
 def apply_ep(params: Dict[str, Any], cfg: ModelConfig, x: jax.Array, ctx) -> jax.Array:
-    import jax.experimental  # noqa: F401  (shard_map is stable in jax>=0.6)
     m = cfg.moe
     b, l, d = x.shape
     ct = cfg.cdtype
@@ -196,14 +195,13 @@ def apply_ep(params: Dict[str, Any], cfg: ModelConfig, x: jax.Array, ctx) -> jax
         # in compute dtype (§Perf: halves the EP all-reduce wire vs f32)
         return jax.lax.psum(y_partial.astype(ct), ctx.model_axis)
 
-    from repro.parallel.mesh_ctx import shard_map
-    y = shard_map(
+    y = jax.shard_map(
         shard,
         mesh=ctx.mesh,
         in_specs=(P_(batch, None), P_(), P_(ctx.model_axis, None, None),
                   P_(ctx.model_axis, None, None), P_(ctx.model_axis, None, None)),
         out_specs=P_(batch, None),
-        check=False,
+        check_vma=False,
     )(x2d, params["router"], params["w_gate"], params["w_up"], params["w_down"])
 
     if m.num_shared:

@@ -18,21 +18,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across versions: jax ≤ 0.4.x only has the
-    experimental entry point; the replication-check kwarg was renamed
-    ``check_rep`` → ``check_vma`` after the promotion to ``jax.shard_map``,
-    so the kwarg is picked off the actual signature."""
-    import inspect
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    kwarg = ("check_vma" if "check_vma" in inspect.signature(sm).parameters
-             else "check_rep")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{kwarg: check})
-
-
 @dataclass(frozen=True)
 class MeshCtx:
     """The distribution environment of the current trace.

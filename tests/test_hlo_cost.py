@@ -85,7 +85,7 @@ def test_real_scan_flops_match_unrolled():
     cs = jax.jit(scanned).lower(ws, x).compile()
     cu = jax.jit(unrolled).lower(ws, x).compile()
     walker = hlo_cost.analyze(cs.as_text(), 1).flops
-    xla_unrolled = hlo_cost.xla_cost_analysis(cu)["flops"]
+    xla_unrolled = cu.cost_analysis()["flops"]
     assert walker == pytest.approx(xla_unrolled, rel=0.05)
 
 

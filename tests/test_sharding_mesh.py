@@ -30,8 +30,9 @@ def test_param_shardings_rules():
     from repro.parallel.mesh_ctx import MeshCtx
     from repro.parallel.sharding import param_shardings
 
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     ctx = MeshCtx(mesh, batch_axes=("pod", "data"), fsdp_axes=("data",))
     cfg = configs.get_smoke("yi-9b")
     tree = lm.init_shapes(cfg)
@@ -59,8 +60,9 @@ def test_moe_ep_equals_ref_on_mesh():
 
     cfg = configs.get_smoke("deepseek-moe-16b")
     m = cfg.moe
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ctx = MeshCtx(mesh, batch_axes=("data",))
     key = jax.random.PRNGKey(0)
     p = moe.init(key, cfg)
@@ -93,8 +95,9 @@ def test_reduced_dryrun_all_kinds():
     from repro.launch import hlo_cost
 
     cfg = configs.get_smoke("gemma2-27b")
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     ctx = MeshCtx(mesh, batch_axes=("pod", "data"), fsdp_axes=("data",))
     B, L = 8, 32
     with mesh_context(ctx):
@@ -148,8 +151,9 @@ def test_flash_decoding_seqshard_matches_plain():
     cache, _ = lm.prefill(params, cfg, toks[:, :-1], max_len=32)
     ref, _ = lm.decode_step(params, cfg, toks[:, -1:], cache)
     # seq-sharded path on a (2,4) mesh
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ctx = MeshCtx(mesh, batch_axes=("data",), shard_kv_seq=True)
     with mesh_context(ctx):
         cache2, _ = jax.jit(lambda p, t: lm.prefill(p, cfg, t, max_len=32)
@@ -180,8 +184,9 @@ def test_elastic_remesh_restore():
     d = tempfile.mkdtemp()
     ckpt.save(state, d, 3)
 
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ctx = MeshCtx(mesh, batch_axes=("data",))
     template = jax.eval_shape(lambda: train_state_init(jax.random.PRNGKey(0), cfg))
     sh = param_shardings(template, ctx)
@@ -205,8 +210,9 @@ def test_seq_shard_reduces_saved_activations():
     from repro.train.step import make_train_step, train_state_shapes
 
     cfg = configs.get_smoke("yi-9b").replace(remat="full")
-    from repro.launch.mesh import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     B, L = 8, 64
     temps = {}
     for seq_shard in (False, True):
