@@ -67,9 +67,6 @@ class TableState:
 
     name: str
     items: Dict[str, Any] = field(default_factory=dict)
-    # op counters for billing / Fig-20 style breakdowns
-    writes: int = 0
-    reads: int = 0
 
     def __post_init__(self):
         self._sorted_keys: List[str] = sorted(self.items)
@@ -78,7 +75,6 @@ class TableState:
 
     def create_if_absent(self, key: str, value: Any) -> bool:
         """Atomic conditional create. True iff the key was absent."""
-        self.writes += 1
         if key in self.items:
             return False
         self.items[key] = _copy_value(value)
@@ -94,14 +90,12 @@ class TableState:
         execution records, counters — that live in the same linearizable
         store but are mutable by design.
         """
-        self.writes += 1
         if key not in self.items:
             insort(self._sorted_keys, key)
         self.items[key] = _copy_value(value)
 
     def get(self, key: str) -> Any:
         """Strongly-consistent read (returns an isolated copy; None if absent)."""
-        self.reads += 1
         val = self.items.get(key)
         return _copy_value(val)
 
@@ -111,7 +105,6 @@ class TableState:
         Creates the list if absent (matches the create-then-append idiom in
         Fig 8 being safe even if the create was lost to a crash).
         """
-        self.writes += 1
         if key in self.items:
             cur = self.items[key]
         else:                       # absent (a stored None is NOT absent)
@@ -124,7 +117,6 @@ class TableState:
 
     def update_bitmap(self, index: int, key: str) -> List[bool]:
         """Atomically set bit ``index`` and return the bitmap (strong read)."""
-        self.writes += 1
         bm = self.items.get(key)
         if bm is None:
             raise KeyError(f"bitmap {key} not created")
@@ -134,7 +126,6 @@ class TableState:
     # -- GC support (§4.4) ----------------------------------------------------
 
     def list_prefix(self, prefix: str) -> List[str]:
-        self.reads += 1
         sk = self._sorted_keys
         i = bisect_left(sk, prefix)
         out: List[str] = []
@@ -153,7 +144,6 @@ class TableState:
                 if i < len(sk) and sk[i] == k:
                     sk.pop(i)
                 n += 1
-        self.writes += len(list(keys))
         return n
 
     # -- introspection ---------------------------------------------------------

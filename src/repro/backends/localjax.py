@@ -52,6 +52,9 @@ def _now_ms() -> float:
     return time.monotonic() * 1e3
 
 
+_DS_READS = frozenset((shim.DsGet, shim.DsListPrefix))   # the rest write
+
+
 class _Killed(BaseException):
     """The current attempt was aborted between two effects (outage /
     injected crash).  A ``BaseException`` so the orchestrator's
@@ -165,7 +168,8 @@ class LocalExecution:
     shared between backends.
     """
 
-    __slots__ = ("runner", "dep", "faas", "record", "gen", "effect_index")
+    __slots__ = ("runner", "dep", "faas", "record", "gen", "effect_index",
+                 "ds_lock")
 
     def __init__(self, runner: "LocalRunner", dep: Deployment,
                  faas: LocalFaaS, record: ExecutionRecord):
@@ -175,6 +179,9 @@ class LocalExecution:
         self.record = record
         self.gen = dep.handler(record.payload)
         self.effect_index = 0
+        # guards the record's datastore counters: ``Parallel`` sub-effects
+        # add to them from several threads at once
+        self.ds_lock = threading.Lock()
 
     def drive(self, value: Any = None) -> Any:
         """Step the effect generator to completion on this thread.  A
@@ -386,12 +393,13 @@ class LocalRunner:
         timer.start()
 
     def _enqueue(self, faas_id_: str, function: str, payload: Any,
-                 attempt: int) -> None:
+                 attempt: int, parent: Optional[int] = None) -> None:
         """Queue an accepted async invocation (at-least-once delivery).
-        The caller has already accounted it in ``_outstanding``."""
+        The caller has already accounted it in ``_outstanding``; ``parent``
+        is the ``exec_id`` of the attempt that invoked it."""
         rec = ExecutionRecord(next(self._exec_ids), function, faas_id_,
                               t_queued=_now_ms(), attempt=attempt,
-                              payload=payload)
+                              payload=payload, parent=parent)
         with self._lock:
             self._index_record(rec)
             self._queues[faas_id_].append(rec)
@@ -434,12 +442,13 @@ class LocalRunner:
             if rec.attempt < self.max_requeues:
                 self._after_ms(self.retry_backoff_ms, self._enqueue,
                                faas.id, rec.function, rec.payload,
-                               rec.attempt + 1)
+                               rec.attempt + 1, rec.parent)
                 return
             self.dropped.append((faas.id, rec.function, rec.payload))
             drop = ExecutionRecord(next(self._exec_ids), rec.function, faas.id,
                                    t_queued=_now_ms(), status="dropped",
-                                   attempt=rec.attempt, payload=rec.payload)
+                                   attempt=rec.attempt, payload=rec.payload,
+                                   parent=rec.parent)
             drop.t_end = drop.t_queued
             self._index_record(drop)
             self._finalize()
@@ -620,7 +629,8 @@ class LocalRunner:
                 f"{effect.function} not deployed on {effect.faas}")
         with self._lock:
             self._outstanding += 1
-        self._enqueue(effect.faas, effect.function, effect.payload, 0)
+        self._enqueue(effect.faas, effect.function, effect.payload, 0,
+                      ex.record.exec_id)
         return True
 
     def _perform_parallel(self, ex: LocalExecution,
@@ -727,6 +737,22 @@ class LocalRunner:
             e["event"].set()                 # release any joined consumer
 
     def _perform_ds(self, ex: LocalExecution, effect: shim.Effect) -> Any:
+        """One datastore effect, counted on the attempt's record: a read or
+        a write, and the time spent here, lock waits included."""
+        t0 = time.perf_counter_ns()
+        try:
+            return self._ds_op(effect)
+        finally:
+            ms = (time.perf_counter_ns() - t0) / 1e6
+            rec = ex.record
+            with ex.ds_lock:
+                if effect.__class__ in _DS_READS:
+                    rec.ds_reads += 1
+                else:
+                    rec.ds_writes += 1
+                rec.ds_ms += ms
+
+    def _ds_op(self, effect: shim.Effect) -> Any:
         st = self.stores.get(getattr(effect, "ds", None))
         if st is None:
             raise shim.DataStoreError(

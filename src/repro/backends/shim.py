@@ -528,6 +528,16 @@ class ExecutionRecord:
     payload: Any = None
     result: Any = None
     phases: List[Tuple[float, str]] = field(default_factory=list)
+    # ``exec_id`` of the attempt whose ``Invoke`` enqueued this one (a
+    # redelivery keeps the parent of the attempt it retries); None for an
+    # external ``submit``, and always None on SimCloud and RemoteRunner,
+    # which do not track it
+    parent: Optional[int] = None
+    # datastore effects this attempt performed and the milliseconds spent
+    # performing them, lock waits included (LocalRunner; 0 elsewhere)
+    ds_reads: int = 0
+    ds_writes: int = 0
+    ds_ms: float = 0.0
 
     def phase_breakdown(self) -> Dict[str, float]:
         """Per-phase elapsed time (Fig-20-style decomposition)."""
