@@ -13,7 +13,7 @@ chunked dual form inside a chunk of ``ssm.chunk`` positions).
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, Tuple
 
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
 
@@ -35,30 +35,50 @@ def _ssm_dims(m: Model):
     return di, di // s["head_dim"], s["head_dim"], s["d_state"], s["d_conv"]
 
 
-def layer_params(m: Model, kind: str) -> int:
-    """Parameters of one layer of ``kind``."""
+def layer_split(m: Model, kind: str) -> Tuple[int, int]:
+    """Parameters of one layer of ``kind``, as (matrices, vectors): the
+    projections, convolution filters and their biases, against the
+    per-channel vectors the math reads in float32 (norm scales, ``A_log``,
+    ``D``, ``dt_bias``)."""
     d = m["d_model"]
     if kind == "attn":
         hd, h, kv = _hd(m), m["n_heads"], m["n_kv_heads"]
-        return (d * h * hd + 2 * d * kv * hd + h * hd * d
-                + 3 * d * m["d_ff"] + 2 * d)
+        return (d * h * hd + 2 * d * kv * hd + h * hd * d      # wq wk wv wo
+                + 3 * d * m["d_ff"],                           # w_gate w_up w_down
+                2 * d)                                         # ln1 ln2
     if kind == "ssm":
         di, nh, _, n, k = _ssm_dims(m)
-        return (2 * d * di + 2 * d * n + d * nh          # wz wx wb wc wdt
-                + (k + 1) * (di + 2 * n)                 # convs and biases
-                + 3 * nh + di * d + d)                   # A_log D dt_bias w_out ln1
+        return (2 * d * di + 2 * d * n + d * nh                # wz wx wb wc wdt
+                + (k + 1) * (di + 2 * n)                       # convs and biases
+                + di * d,                                      # w_out
+                3 * nh + d)                                    # A_log D dt_bias ln1
     raise ValueError(f"unknown layer kind {kind!r}")
+
+
+def layer_params(m: Model, kind: str) -> int:
+    """Parameters of one layer of ``kind``."""
+    return sum(layer_split(m, kind))
 
 
 def decode_weight_bytes(m: Model, batch: int) -> int:
     """Weight bytes one decode step reads: every layer, the final norm, the
     head over the real vocabulary, and the embedding rows of the batch
-    (the whole table when the head is tied to it)."""
+    (the whole table when the head is tied to it).
+
+    A step needs each weight in the dtype it multiplies in: matrices count
+    at the narrower of ``param_dtype`` and ``compute_dtype``, since a wider
+    master copy is the implementation's choice and not the model's need.
+    Per-channel vectors count at ``param_dtype``, as the math reads them
+    in float32."""
     d, v = m["d_model"], m["vocab"]
-    n = sum(layer_params(m, k) for k in _kinds(m)) + d + v * d
+    mats, vecs = map(sum, zip(*(layer_split(m, k) for k in _kinds(m))))
+    mats += v * d
     if not m.get("tie_embeddings", False):
-        n += batch * d
-    return n * DTYPE_BYTES[m.get("param_dtype", "float32")]
+        mats += batch * d
+    vecs += d                                                  # final norm
+    pb = DTYPE_BYTES[m.get("param_dtype", "float32")]
+    cb = min(pb, DTYPE_BYTES[m.get("compute_dtype", "bfloat16")])
+    return mats * cb + vecs * pb
 
 
 def _layer_prefill_flops(m: Model, kind: str, length: int) -> float:

@@ -24,7 +24,13 @@ def stage_mfu(run) -> Optional[float]:
 def decode_roofline(run) -> Optional[float]:
     """Least time a decode step could take (the bytes it must move at peak
     HBM bandwidth) over the mean device time of the decode program's runs
-    in the trace, in %."""
+    in the trace, in %.
+
+    A step needs each weight matrix in the dtype it multiplies in, so the
+    bytes count matrices at the compute dtype even where the program keeps
+    a wider master copy: reading and casting that copy on every step is
+    time the program spends, not bytes the model needs
+    (``flops.decode_weight_bytes``)."""
     if run.trace is None:
         return None
     times = run.trace.module_seconds(DECODE_PROGRAM)

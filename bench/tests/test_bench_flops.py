@@ -1,13 +1,17 @@
-"""bench/flops.py against hand counts at a smoke size, and its parameter
-count against the program's own parameter tree."""
+"""bench/flops.py against hand counts at a smoke size and at the cells'
+sizes, and its parameter count, matrices and vectors apart, against the
+program's own parameter tree."""
 
+import json
 import math
 import os
 import sys
 
 import jax
+import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, BENCH)
 
 import flops  # noqa: E402
 from harness.stage import model_config  # noqa: E402
@@ -58,11 +62,19 @@ def test_request_is_prefill_then_new_tokens_minus_one_steps():
     assert flops.request_flops(LLAMA, req) == want
 
 
-def _program_layer_params(model):
+# leaves the math reads in float32 whatever the master dtype
+_VECTORS = {"ln1", "ln2", "A_log", "D", "dt_bias"}
+
+
+def _program_block_leaves(model):
     cfg = model_config(model)
     shapes = jax.eval_shape(lambda k: lm.init(k, cfg), jax.random.PRNGKey(0))
-    blocks = jax.tree.leaves(shapes["blocks"])
-    return sum(math.prod(s.shape) for s in blocks) // model["n_layers"]
+    return jax.tree_util.tree_flatten_with_path(shapes["blocks"])[0]
+
+
+def _program_layer_params(model):
+    blocks = _program_block_leaves(model)
+    return sum(math.prod(s.shape) for _, s in blocks) // model["n_layers"]
 
 
 def test_layer_params_match_the_program_tree():
@@ -70,9 +82,23 @@ def test_layer_params_match_the_program_tree():
     assert flops.layer_params(LLAMA, "attn") == 34944 == _program_layer_params(LLAMA)
 
 
+@pytest.mark.parametrize("model,kind", [(MAMBA, "ssm"), (LLAMA, "attn")])
+def test_layer_split_matches_the_program_tree(model, kind):
+    split = {True: 0, False: 0}
+    for path, s in _program_block_leaves(model):
+        split[path[-1].key in _VECTORS] += math.prod(s.shape) // model["n_layers"]
+    mats, vecs = flops.layer_split(model, kind)
+    assert (mats, vecs) == (split[False], split[True])
+    assert mats + vecs == flops.layer_params(model, kind)
+
+
 def test_decode_bytes_by_hand():
-    # tied head: the whole table is read for the logits, f32 weights
-    assert flops.decode_weight_bytes(MAMBA, 4) == (2 * 28024 + 64 + 512 * 64) * 4
+    # tied head: the whole table is read for the logits; f32 master weights
+    # under bf16 compute: matrices at 2 bytes, vectors (A_log D dt_bias ln1,
+    # final norm) at 4
+    assert flops.layer_split(MAMBA, "ssm") == (27936, 88)
+    assert flops.decode_weight_bytes(MAMBA, 4) == \
+        (2 * 27936 + 512 * 64) * 2 + (2 * 88 + 64) * 4
     # untied: the head plus the batch's embedding rows, bf16
     assert flops.decode_weight_bytes(LLAMA, 4) == \
         (2 * 34944 + 64 + 512 * 64 + 4 * 64) * 2
@@ -81,3 +107,26 @@ def test_decode_bytes_by_hand():
     # the SSM state [H, P, N] f32 and the conv tails, read and written
     assert flops.decode_state_bytes(MAMBA, 1, 32) == \
         2 * 2 * (8 * 16 * 16 * 4 + 3 * (128 + 32) * 2)
+
+
+def test_master_dtype_moves_only_the_vectors():
+    # at bf16 compute, a f32 master copy adds only the vectors' extra bytes
+    bf16 = dict(MAMBA, param_dtype="bfloat16")
+    vecs = 2 * flops.layer_split(MAMBA, "ssm")[1] + MAMBA["d_model"]
+    assert flops.decode_weight_bytes(MAMBA, 4) \
+        - flops.decode_weight_bytes(bf16, 4) == vecs * (4 - 2)
+    assert flops.decode_state_bytes(MAMBA, 4, 32) == \
+        flops.decode_state_bytes(bf16, 4, 32)
+
+
+@pytest.mark.parametrize("config,traffic,weights,mean", [
+    ("qa-mamba2-370m", "short-burst", 736589824, 1144551424),
+    ("qa-yi-9b", "longdoc-closed8", 6061072384, 6197518336),
+])
+def test_cells_decode_bytes(config, traffic, weights, mean):
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
+        model = json.load(f)["model"]
+    with open(os.path.join(BENCH, "traffic", traffic + ".json")) as f:
+        request = json.load(f)["request"]
+    assert flops.decode_weight_bytes(model, request["prompts"]) == weights
+    assert flops.mean_decode_bytes(model, request) == mean
