@@ -122,6 +122,69 @@ def init_shapes(cfg: ModelConfig, seed: int = 0):
 
 
 # ==========================================================================
+# Compute-dtype copy of the weights (the served path casts once)
+# ==========================================================================
+
+_ATTN_CAST = frozenset({"wq", "wk", "wv", "wo", "bq", "bk", "bv"})
+# Leaves the forward and decode read only through ``.astype(cfg.cdtype)``,
+# by the key of the module that owns them.  Left out on purpose: norm
+# scales (``rms_norm`` reads them at float32), and ``A_log``, ``dt_bias``
+# and ``D``, which ``ssm.decode_step`` reads at float32.
+_CAST_LEAVES = {
+    "ssm": frozenset({"wz", "wx", "wb", "wc", "wdt", "w_out",
+                      "conv_x_w", "conv_x_b", "conv_b_w", "conv_b_b",
+                      "conv_c_w", "conv_c_b"}),
+    "attn": _ATTN_CAST,
+    "xattn": _ATTN_CAST,
+    "mlp": frozenset({"w_gate", "w_up", "w_down"}),
+}
+_CAST_TOP = frozenset({"embed", "lm_head"})      # the tied or untied head
+
+
+def _cast_by_rule(path) -> bool:
+    keys = [getattr(k, "key", None) for k in path]
+    if len(keys) == 1:
+        return keys[0] in _CAST_TOP
+    return keys[-1] in _CAST_LEAVES.get(keys[-2], ())
+
+
+def compute_params(params, cfg: ModelConfig):
+    """The tree with every leaf that the model reads only as
+    ``leaf.astype(cfg.cdtype)`` already in ``cfg.cdtype``.
+
+    Those are the SSM block's projections and depthwise-conv filters and
+    biases, attention's (and cross-attention's) projections and biases,
+    the MLP's matrices, and the embedding table and untied head.  Casting
+    them ahead gives the programs the same operands, bit for bit, so a
+    served path can cast once per weight set instead of on every call.
+    Every other leaf is returned as it is: the norm scales, which
+    ``rms_norm`` reads at float32; ``A_log``, ``dt_bias`` and ``D``, which
+    ``ssm.decode_step`` reads at float32 (prefill casts ``D``, decode does
+    not); and whatever the rule does not name (MoE, RG-LRU, front ends),
+    which stays correct and only keeps its per-call cast.
+
+    Returns ``params`` itself when every leaf the rule names is already in
+    ``cfg.cdtype``, so a tree served at its own dtype gains no copy.
+    Abstract leaves (``ShapeDtypeStruct``) map to abstract leaves of
+    ``cfg.cdtype`` with their sharding kept.
+    """
+    ct = cfg.cdtype
+    named = [x for path, x in jax.tree_util.tree_leaves_with_path(params)
+             if _cast_by_rule(path)]
+    if all(x.dtype == ct for x in named):
+        return params
+
+    def cast(path, x):
+        if not _cast_by_rule(path) or x.dtype == ct:
+            return x
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(x.shape, ct, sharding=x.sharding)
+        return x.astype(ct)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
+# ==========================================================================
 # Block application (train / prefill)
 # ==========================================================================
 
