@@ -44,7 +44,7 @@ def main(argv=None, *, platform: str = "tpu", root=None) -> int:
         driver = cli.prepare(cell, seed)
         w = driver.window(cell.traffic["arrivals"], seed, args.seconds)
         v = check.check(w, cell.config["workflow"]["functions"][0]["name"],
-                        seed, cell.limits, control=True)
+                        seed, cell.limits, cell.reference, control=True)
         if v.control.correct or not v.correct:
             rc = 1
         print(json.dumps({"seed": seed, "instances": len(w.instances),

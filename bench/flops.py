@@ -9,6 +9,12 @@ real vocabulary and the rows it reads.  A multiply-add is two operations.
 Layer kinds: ``attn`` (grouped-query attention with a gated-SiLU MLP, the
 llama family) and ``ssm`` (Mamba-2's SSD block, single group, with its
 chunked dual form inside a chunk of ``ssm.chunk`` positions).
+
+The default counts of every cell (``spec.counts_of``).  A configuration
+with layers this module does not count names a counts module of its own
+in its file (``"counts"``); ``harness/layers.py`` calls its
+``request_flops(model, request)`` and ``mean_decode_bytes(model,
+request)``, and it may build on the functions here.
 """
 
 from __future__ import annotations
@@ -20,8 +26,18 @@ DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
 Model = Dict[str, Any]
 
 
-def _kinds(m: Model):
+def kinds(m: Model):
+    """The kind of each of the model's layers: ``layer_pattern`` cycled
+    over ``n_layers``.  Raises ``ValueError`` for a model with layers this
+    module does not count (a kind other than ``attn`` and ``ssm``, or
+    experts), which would otherwise be counted as something else."""
     pattern = m.get("layer_pattern", ["attn"])
+    other = sorted(set(pattern) - {"attn", "ssm"})
+    if m.get("moe"):
+        other.append("moe")
+    if other:
+        raise ValueError(f"bench/flops.py counts no {other} layers: the "
+                         f"configuration names counts of its own")
     return [pattern[i % len(pattern)] for i in range(m["n_layers"])]
 
 
@@ -71,7 +87,7 @@ def decode_weight_bytes(m: Model, batch: int) -> int:
     Per-channel vectors count at ``param_dtype``, as the math reads them
     in float32."""
     d, v = m["d_model"], m["vocab"]
-    mats, vecs = map(sum, zip(*(layer_split(m, k) for k in _kinds(m))))
+    mats, vecs = map(sum, zip(*(layer_split(m, k) for k in kinds(m))))
     mats += v * d
     if not m.get("tie_embeddings", False):
         mats += batch * d
@@ -114,13 +130,13 @@ def _layer_decode_flops(m: Model, kind: str, pos: int) -> float:
 def prefill_flops(m: Model, batch: int, length: int) -> float:
     """A prefill of ``batch`` prompts of ``length``, with the logits of the
     last position."""
-    per_seq = sum(_layer_prefill_flops(m, k, length) for k in _kinds(m))
+    per_seq = sum(_layer_prefill_flops(m, k, length) for k in kinds(m))
     return batch * (per_seq + 2 * m["d_model"] * m["vocab"])
 
 
 def decode_flops(m: Model, batch: int, pos: int) -> float:
     """One decode step of ``batch`` tokens at absolute position ``pos``."""
-    per_tok = sum(_layer_decode_flops(m, k, pos) for k in _kinds(m))
+    per_tok = sum(_layer_decode_flops(m, k, pos) for k in kinds(m))
     return batch * (per_tok + 2 * m["d_model"] * m["vocab"])
 
 
@@ -130,7 +146,7 @@ def decode_state_bytes(m: Model, batch: int, pos: int) -> int:
     writes back."""
     cb = DTYPE_BYTES[m.get("compute_dtype", "bfloat16")]
     total = 0
-    for kind in _kinds(m):
+    for kind in kinds(m):
         if kind == "attn":
             total += 2 * (pos + 2) * m["n_kv_heads"] * _hd(m) * cb
         else:

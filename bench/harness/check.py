@@ -7,7 +7,8 @@ Two layers are held to it.
   what the stage computed for that instance, of the request's shape and in
   the vocabulary.
 * Model stage: a sample of the served instances, drawn from the seed, goes
-  through the plain float32 reference, once over each prompt with the
+  through the plain float32 reference that the configuration names
+  (``spec.reference_of``), once over each prompt with the
   tokens served after it.  At every served position the number read is
   how far the served token's reference logit lies below the reference's
   best, over the spread (standard deviation) of that position's logits.
@@ -28,11 +29,10 @@ from __future__ import annotations
 
 import random
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import numpy as np
-
-from harness import reference
 
 
 def _done(inst, function: str):
@@ -80,8 +80,10 @@ class Gaps:
     control: Optional[np.ndarray] = None  # [P, T] gap of the control's token
 
 
-def gaps(window, entry: str, inst, control: bool = False) -> Gaps:
-    """Gaps over std at the served positions of one instance."""
+def gaps(window, entry: str, inst, reference: ModuleType,
+         control: bool = False) -> Gaps:
+    """Gaps over std at the served positions of one instance, by the
+    ``reference`` module's ``logits``."""
     stage = window.stage
     prompts = np.asarray(_done(inst, entry)[0].result["prompts"], np.int32)
     served = np.asarray(stage.outputs[inst.index], np.int32)
@@ -134,11 +136,13 @@ def _judge(window, faults: Dict[int, str], widest: Dict[int, float],
     return Verdict(correct, len(faults), numbers, faults)
 
 
-def check(window, entry: str, seed: int, limits: Dict, control: bool = False
-          ) -> Verdict:
+def check(window, entry: str, seed: int, limits: Dict, reference: ModuleType,
+          control: bool = False) -> Verdict:
+    """The verdict on a window, with ``reference`` the cell's plain
+    reference (``Cell.reference``)."""
     faults = workflow_faults(window, entry)
     picked = sample(window, seed, int(limits["sample_instances"]), faults)
-    got = {inst.index: gaps(window, entry, inst, control=control)
+    got = {inst.index: gaps(window, entry, inst, reference, control=control)
            for inst in picked}
     verdict = _judge(window, faults, {i: float(np.max(g.served))
                                       for i, g in got.items()}, limits)

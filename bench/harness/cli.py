@@ -154,7 +154,7 @@ def prepare(cell: Cell, seed: int) -> Driver:
     """Set-up: weights from the seed, the cell's programs compiled (or
     loaded) and run once, one workflow instance end to end."""
     model = cell.config["model"]
-    params = make_weights(model_config(model), seed)
+    params = make_weights(model_config(model), seed, cell.config.get("draw"))
     stage = Stage(model, params, cell.traffic["request"], seed)
     stage.warm_up()
     driver = Driver(cell.config["workflow"], stage)
@@ -198,7 +198,8 @@ def _run(args, cell: Cell, t_start: float, devices, compiles: List[float],
         if value is not None:
             metrics[m.name] = {"value": value, "unit": m.unit}
 
-    verdict = check.check(window, run.entry, args.seed, cell.limits)
+    verdict = check.check(window, run.entry, args.seed, cell.limits,
+                          cell.reference)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
               "count": cell.chips, "memory_peak_bytes": int(peak)}
     if summary is not None:

@@ -1,10 +1,13 @@
-"""Per-layer numbers that more than one reader shares."""
+"""Per-layer numbers that more than one reader shares.
+
+Operations and bytes come from the cell's counts module
+(``spec.counts_of``: ``bench/flops.py`` unless the configuration file names
+its own), so a configuration with layers of its own brings their counts
+and its roofline readers stay three lines."""
 
 from __future__ import annotations
 
 from typing import Optional
-
-import flops
 
 DECODE_PROGRAM = "jit_decode_step"     # the program's jitted decode step
 
@@ -16,7 +19,7 @@ def stage_mfu(run) -> Optional[float]:
     calls = [c for c in w.stage.calls if w.t0_ms <= c[2] <= w.t1_ms]
     if not calls:
         return None
-    ops = len(calls) * flops.request_flops(run.model, run.request)
+    ops = len(calls) * run.cell.counts.request_flops(run.model, run.request)
     peak = run.peaks()["bf16_flops_per_s"] * run.cell.chips
     return 100.0 * ops / (w.seconds * peak)
 
@@ -30,13 +33,13 @@ def decode_roofline(run) -> Optional[float]:
     bytes count matrices at the compute dtype even where the program keeps
     a wider master copy: reading and casting that copy on every step is
     time the program spends, not bytes the model needs
-    (``flops.decode_weight_bytes``)."""
+    (``flops.decode_weight_bytes`` in the default counts)."""
     if run.trace is None:
         return None
     times = run.trace.module_seconds(DECODE_PROGRAM)
     if not times:
         return None
-    need = flops.mean_decode_bytes(run.model, run.request) \
+    need = run.cell.counts.mean_decode_bytes(run.model, run.request) \
         / run.peaks()["hbm_bytes_per_s"]
     return 100.0 * need / (sum(times) / len(times))
 

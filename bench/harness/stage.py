@@ -4,34 +4,56 @@ QA workflow (``sort`` builds an instance's prompts, ``qa`` serves them).
 
 The weights are the benchmark's, not the program's: one jitted call draws
 every leaf of the program's parameter tree from the seed, in the dtype it
-is served in.  The plain reference reads the same arrays.
+is served in.  The plain reference reads the same arrays.  A configuration
+file's ``draw`` table names rules for leaves the built-in table lacks.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import threading
 import time
-from typing import Any, Dict, List
+import typing
+from typing import Any, Dict, List, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.models import lm
-from repro.models.common import ModelConfig, SSMConfig
+from repro.models.common import ModelConfig
 from repro.serve import engine
+
+
+def _typed(hint, value):
+    """``value`` from a JSON file as the field typed ``hint`` holds it: a
+    dict becomes the dataclass it names, a list the tuple."""
+    if value is None:
+        return None
+    args = [a for a in typing.get_args(hint) if a is not type(None)]
+    if typing.get_origin(hint) is typing.Union and len(args) == 1:
+        hint = args[0]
+    if dataclasses.is_dataclass(hint) and isinstance(value, Mapping):
+        return _build(hint, value)
+    if (hint is tuple or typing.get_origin(hint) is tuple) \
+            and isinstance(value, list):
+        return tuple(value)
+    return value
+
+
+def _build(cls, raw: Mapping[str, Any]):
+    hints = typing.get_type_hints(cls)
+    return cls(**{k: _typed(hints[k], v) if k in hints else v
+                  for k, v in raw.items()})
 
 
 def model_config(model: Dict[str, Any]) -> ModelConfig:
     """The program's config object from the ``model`` block of a
-    configuration file."""
-    kw = dict(model)
-    if "layer_pattern" in kw:
-        kw["layer_pattern"] = tuple(kw["layer_pattern"])
-    if kw.get("ssm") is not None:
-        kw["ssm"] = SSMConfig(**kw["ssm"])
-    return ModelConfig(**kw)
+    configuration file.  Every dataclass-typed field (``moe``, ``ssm``,
+    ``rglru``, any later sub-config) is built from its dict, and a list
+    becomes a tuple where the field is one, by the fields' type hints."""
+    return _build(ModelConfig, model)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -46,10 +68,39 @@ _PROJECTIONS = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "lm_head",
 _NORMS = {"ln1", "ln2", "final_norm"}
 
 
-def _leaf(name: str, shape, key) -> jax.Array:
+def _drawn(rule: Mapping[str, Any], shape, normal, uniform) -> jax.Array:
+    """A leaf by one rule of a configuration's ``draw`` table."""
+    (kind, arg), = rule.items()
+    if kind == "normal":
+        return normal(1.0 / math.sqrt(shape[-2]) if arg == "fan_in"
+                      else float(arg))
+    if kind == "uniform":
+        return uniform(float(arg[0]), float(arg[1]))
+    if kind == "log_uniform":
+        return jnp.exp(uniform(math.log(arg[0]), math.log(arg[1])))
+    if kind == "const":
+        return jnp.full(shape, float(arg), jnp.float32)
+    raise ValueError(f"unknown draw rule {rule!r}")
+
+
+def _rule(path, draw: Mapping[str, Any]) -> Optional[Mapping[str, Any]]:
+    """The ``draw`` entry for a leaf: its ``parent/leaf`` suffix first,
+    then its name."""
+    keys = [str(getattr(k, "key", k)) for k in path]
+    for n in (2, 1):
+        if len(keys) >= n and "/".join(keys[-n:]) in draw:
+            return draw["/".join(keys[-n:])]
+    return None
+
+
+def _leaf(path, shape, key, draw: Mapping[str, Any]) -> jax.Array:
     normal = lambda s: jax.random.normal(key, shape, jnp.float32) * s  # noqa: E731
     uniform = lambda lo, hi: jax.random.uniform(  # noqa: E731
         key, shape, jnp.float32, lo, hi)
+    rule = _rule(path, draw)
+    if rule is not None:
+        return _drawn(rule, shape, normal, uniform)
+    name = path[-1].key
     if name in _PROJECTIONS:
         return normal(1.0 / math.sqrt(shape[-2]))
     if name == "embed":
@@ -70,14 +121,24 @@ def _leaf(name: str, shape, key) -> jax.Array:
     raise KeyError(f"no rule to draw parameter {name!r}")
 
 
-def make_weights(cfg: ModelConfig, seed: int):
-    """Every parameter from the seed, on the device, in one jitted call."""
+def make_weights(cfg: ModelConfig, seed: int,
+                 draw: Optional[Mapping[str, Any]] = None):
+    """Every parameter from the seed, on the device, in one jitted call.
+
+    ``draw`` (a configuration file's table) maps a leaf's name, or the
+    ``parent/leaf`` end of its path, to a rule: ``{"normal": std}``,
+    ``{"normal": "fan_in"}`` (std 1/sqrt(shape[-2])), ``{"uniform": [lo,
+    hi]}``, ``{"log_uniform": [lo, hi]}`` or ``{"const": v}``.  It is read
+    before the built-in rules; a leaf that neither names raises
+    ``KeyError``.  Each leaf's key is split off in the tree's path order,
+    so a table changes no other leaf's draw."""
+    draw = draw or {}
     shapes = jax.eval_shape(lambda k: lm.init(k, cfg), jax.random.PRNGKey(0))
     paths, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
     def build(key):
         keys = jax.random.split(key, len(paths))
-        leaves = [_leaf(path[-1].key, sds.shape, k).astype(sds.dtype)
+        leaves = [_leaf(path, sds.shape, k, draw).astype(sds.dtype)
                   for (path, sds), k in zip(paths, keys)]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 

@@ -5,6 +5,7 @@ width and two layers, short prompts, and a small limit file."""
 import json
 import sys
 from pathlib import Path
+from typing import Any, Dict, Optional
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -15,19 +16,43 @@ sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 TEST_LIMIT = 0.1
 
 
-def build(dst: Path, d_model: int = 64, new_tokens: int = 4) -> Path:
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+def _merged(base: Dict[str, Any], over: Dict[str, Any]) -> Dict[str, Any]:
+    """``base`` with ``over`` merged in, nested groups key by key."""
+    out = dict(base)
+    for k, v in over.items():
+        nest = isinstance(v, dict) and isinstance(base.get(k), dict)
+        out[k] = _merged(base[k], v) if nest else v
+    return out
+
+
+def smoke_model(config: Dict[str, Any], d_model: int = 64) -> Dict[str, Any]:
+    """A configuration file's model at the smoke size: two layers of width
+    ``d_model`` over a vocabulary of 1024, then the file's own ``smoke``
+    overrides, or by default a rule by ``family`` (SSD at state 32, heads
+    of 32 and chunks of 32; otherwise heads of 32 and ``d_ff`` 4 x width)."""
+    m = dict(config["model"], n_layers=2, d_model=d_model, vocab=1024)
+    if "smoke" in config:
+        return _merged(m, config["smoke"])
+    if m["family"] == "ssm":
+        m["ssm"] = dict(m["ssm"], d_state=32, head_dim=32, chunk=32)
+    else:
+        m.update(n_heads=d_model // 32, n_kv_heads=max(1, d_model // 128),
+                 d_ff=4 * d_model)
+    return m
+
+
+def build(dst: Path, d_model: int = 64, new_tokens: int = 4,
+          bench: Optional[Dict[str, Any]] = None) -> Path:
+    """The copy in ``dst``, of ``bench`` (by default ``BENCHMARK.json``):
+    its configuration files at the smoke size, its traffic files at short
+    prompts and low rates, and a limit file for each cell."""
+    bench = bench or json.loads((ROOT / "BENCHMARK.json").read_text())
     for sub in ("configs", "traffic", "limits"):
         (dst / "bench" / sub).mkdir(parents=True, exist_ok=True)
     for c in bench["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
-        m = cfg["model"]
-        m.update(n_layers=2, d_model=d_model, vocab=1024)
-        if m["family"] == "ssm":
-            m["ssm"].update(d_state=32, head_dim=32, chunk=32)
-        else:
-            m.update(n_heads=d_model // 32, n_kv_heads=max(1, d_model // 128),
-                     d_ff=4 * d_model)
+        cfg["model"] = smoke_model(cfg, d_model)
+        (dst / c["file"]).parent.mkdir(parents=True, exist_ok=True)
         (dst / c["file"]).write_text(json.dumps(cfg))
     for w in bench["workloads"]:
         t = json.loads((BENCH / "traffic" / f"{w['traffic']}.json").read_text())

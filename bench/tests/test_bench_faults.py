@@ -82,7 +82,7 @@ def test_control_reads_above_the_limit(root):
     cell = load_cell(CELL, root)
     driver = cli.prepare(cell, SEED)
     w = driver.window(cell.traffic["arrivals"], SEED, 2.0)
-    v = check.check(w, "sort", SEED, cell.limits, control=True)
+    v = check.check(w, "sort", SEED, cell.limits, cell.reference, control=True)
     assert v.correct and not v.control.correct
     assert v.control.failed > 0
     assert v.numbers["gap_over_std"]["value"] < bench_smoke.TEST_LIMIT \
